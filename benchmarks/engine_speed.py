@@ -21,7 +21,9 @@ Emits ``results/bench/engine_speed.csv`` (full table) and
 trajectory point future PRs diff against.
 
 Device-scaling study (``python -m benchmarks.engine_speed --scaling``):
-times the grid-cells workload at 1/2/4/8 *virtual host devices*
+a virtual-CPU study, refused on any other platform (each child would need
+the backend this process already holds). It times the grid-cells workload
+at 1/2/4/8 *virtual host devices*
 (``xla_force_host_platform_device_count``), each count in its own
 subprocess because the flag is XLA-pre-init only. The sharded leg runs
 the engine's ``devices=N`` dispatch — one jitted executable whose batch
@@ -225,6 +227,10 @@ def _time_scaling_leg(cells, ndev: int) -> dict:
 
 
 def run_scaling():
+    platform = jax.devices()[0].platform
+    if platform != "cpu":
+        raise SystemExit(f"--scaling is a virtual-CPU study; refusing to run "
+                         f"on the {platform} backend (use JAX_PLATFORMS=cpu)")
     name, cells = _workloads()[0]  # grid-cells: 18 cells x 8 seeds = 144
     rows = []
     for ndev in SCALING_DEVICES:

@@ -1,8 +1,11 @@
 #!/usr/bin/env python
 """Multi-device smoke: ``devices=N`` must be bit-identical to single-device.
 
-Forces ``N`` virtual host devices (``repro.config.set_host_devices`` must
-run before jax initializes, so this script applies it itself) and runs the
+A virtual-CPU study: it refuses to run on any platform but the CPU (on an
+accelerator host, ``python chip_smoke.py --chips 4`` checks the same
+property on real chips). Forces ``N`` virtual host devices
+(``repro.config.set_host_devices`` must run before jax initializes, so
+this script applies it itself) and runs the
 scenario engine's sharded dispatch — one jitted executable whose batch
 axis is split over a 1-D ``shard_map`` mesh (``scenarios._compile_runner``)
 — against the plain single-device runner on the same cells, for all FOUR
@@ -29,8 +32,8 @@ from repro import config as CFG  # noqa: E402
 if "--xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
     CFG.set_host_devices(N)
-# topology-keyed persistent compile cache (entries are not portable
-# across device counts — see repro.config.cache_dir)
+# persistent compile cache: JAX_COMPILATION_CACHE_DIR as set, else the
+# in-checkout directory of this device count (repro.config.cache_dir)
 os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", CFG.cache_dir(N))
 
 import jax  # noqa: E402
@@ -60,6 +63,11 @@ def _diff(tag: str, a, b) -> int:
 
 
 def main() -> int:
+    platform = jax.devices()[0].platform
+    if platform != "cpu":
+        print(f"REFUSED: {platform} backend; this is a virtual-CPU study "
+              "(run with JAX_PLATFORMS=cpu)")
+        return 2
     avail = jax.local_device_count()
     if avail < N:
         print(f"FAIL: {avail} local device(s), need {N} "

@@ -16,11 +16,11 @@ Environment knobs (all optional):
   (``jax_platform_name``; GPU also gets the XLA perf-flag recipe).
 * ``REPRO_X64`` — truthy enables float64 (``jax_enable_x64``).
 * ``REPRO_DEBUG_NANS`` — truthy enables ``jax_debug_nans``.
-* ``JAX_COMPILATION_CACHE_DIR`` — persistent compile cache. Entries are
-  NOT portable across host topologies (the cache key does not cover the
-  device-count flag, and replaying a foreign-topology entry returns
-  corrupted executables — see :func:`cache_dir`), so the directory is
-  always keyed by the device count.
+* ``JAX_COMPILATION_CACHE_DIR`` — persistent compile cache. When set, that
+  exact directory is used (and handed to child processes unchanged); when
+  unset, the cache lives inside the checkout at ``.jax_cache/d<N>``,
+  keyed by the virtual host-device count — see :func:`cache_dir`. Each
+  process places its own cache with :func:`apply_cache`.
 
 Import discipline: this module never imports ``jax`` at the top level, so
 the pre-init knobs (:func:`set_host_devices`, :func:`cache_dir`,
@@ -37,11 +37,13 @@ import sys
 # Matches the device-count flag (with its value) inside an XLA_FLAGS string.
 _DEVICE_FLAG_RE = re.compile(
     r"--xla_force_host_platform_device_count=\d+\s*")
-# A cache dir already keyed by device count ("...-d8") — see cache_base().
-_CACHE_KEY_RE = re.compile(r"-d\d+$")
 
-DEFAULT_CACHE_BASE = os.path.join(
-    os.path.expanduser("~"), ".cache", "repro-jax-cache")
+# In-checkout home of the compile cache when JAX_COMPILATION_CACHE_DIR is
+# unset (listed in .gitignore). A fixed path: the directory is part of what
+# makes a later run find its entries again.
+DEFAULT_CACHE_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 # The XLA perf-flag recipe for GPU runs (bayespec's set_platform; see
 # https://jax.readthedocs.io/en/latest/gpu_performance_tips.html).
@@ -78,41 +80,32 @@ def set_host_devices(devices: int) -> None:
         devices, os.environ.get("XLA_FLAGS"))
 
 
-def cache_base(env: dict | None = None) -> str:
-    """Un-keyed base path of the persistent compilation cache.
+def host_devices(env: dict | None = None) -> int:
+    """Virtual host device count requested by ``XLA_DEVICES`` (default 1)."""
+    env = os.environ if env is None else env
+    return int(env.get("XLA_DEVICES") or 1)
 
-    Resolution order: ``REPRO_JAX_CACHE_BASE``, then
-    ``JAX_COMPILATION_CACHE_DIR`` with any existing ``-d<N>`` topology
-    suffix stripped (so consumers can re-key an already-keyed dir), then
-    :data:`DEFAULT_CACHE_BASE`.
+
+def cache_dir(devices: int = 1, env: dict | None = None) -> str:
+    """Persistent compilation-cache directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` exactly as set, if it is set. Otherwise
+    ``<checkout>/.jax_cache/d<devices>``: one directory per virtual host
+    device count, so CPU runs at different topologies never replay each
+    other's entries.
     """
     env = os.environ if env is None else env
-    base = env.get("REPRO_JAX_CACHE_BASE")
-    if base:
-        return base
-    cur = env.get("JAX_COMPILATION_CACHE_DIR")
-    if cur:
-        return _CACHE_KEY_RE.sub("", cur)
-    return DEFAULT_CACHE_BASE
-
-
-def cache_dir(devices: int, env: dict | None = None) -> str:
-    """Compilation-cache directory keyed by host topology.
-
-    The cache key does NOT cover ``xla_force_host_platform_device_count``;
-    replaying an entry compiled under a different topology returns
-    corrupted executables (uninitialized output buffers — bitten by the
-    8-device CI leg), so every device count gets its own directory.
-    """
-    return f"{cache_base(env)}-d{int(devices)}"
+    return (env.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(DEFAULT_CACHE_ROOT, f"d{int(devices)}"))
 
 
 def subprocess_env(devices: int, env: dict | None = None) -> dict:
     """Environment for a child process pinned to ``devices`` host devices.
 
-    Sets the device-count flag (pre-init, so the child sees it) and a
-    topology-keyed compilation-cache dir. Used by ``tests/conftest.py``
-    and the device-scaling study in ``benchmarks/engine_speed.py``.
+    Sets the device-count flag (pre-init, so the child sees it) and the
+    compilation-cache dir of :func:`cache_dir`. Used by
+    ``tests/conftest.py`` and the device-scaling study in
+    ``benchmarks/engine_speed.py``.
     """
     env = dict(os.environ if env is None else env)
     env["XLA_FLAGS"] = device_flags(devices, env.get("XLA_FLAGS"))
@@ -121,6 +114,16 @@ def subprocess_env(devices: int, env: dict | None = None) -> dict:
 
 
 # ----------------------------------------------------- jax.config knobs ---
+def apply_cache(devices: int = 1, env: dict | None = None) -> str:
+    """Point THIS process's persistent compilation cache at
+    :func:`cache_dir` and return the directory."""
+    import jax
+
+    path = cache_dir(devices, env)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def enable_x64(use_x64: bool = True) -> None:
     """Default JAX arrays to float64 (else float32)."""
     import jax
@@ -164,12 +167,11 @@ def apply_env(env: dict | None = None) -> dict:
     """
     env = os.environ if env is None else env
     applied: dict = {}
-    devices = env.get("XLA_DEVICES")
-    if devices:
-        set_host_devices(int(devices))
-        os.environ.setdefault(
-            "JAX_COMPILATION_CACHE_DIR", cache_dir(int(devices), env))
-        applied["devices"] = int(devices)
+    devices = host_devices(env)
+    if env.get("XLA_DEVICES"):
+        set_host_devices(devices)
+        applied["devices"] = devices
+    applied["cache_dir"] = apply_cache(devices, env)
     if env.get("REPRO_PLATFORM"):
         set_platform(env["REPRO_PLATFORM"])
         applied["platform"] = env["REPRO_PLATFORM"]
@@ -186,16 +188,16 @@ def apply_env(env: dict | None = None) -> dict:
 def shell_exports(env: dict | None = None) -> list[str]:
     """``export KEY="VAL"`` lines for shell consumers (scripts/test.sh).
 
-    Derives XLA_FLAGS (device count from ``XLA_DEVICES``, default 1) and
-    a topology-keyed JAX_COMPILATION_CACHE_DIR from the same rules the
-    Python consumers use, so bash and Python can never drift.
+    Derives XLA_FLAGS (device count from ``XLA_DEVICES``, default 1) from
+    the same rules the Python consumers use, so bash and Python can never
+    drift. The compile cache is deliberately not exported: every process
+    places its own (:func:`apply_cache`), so a ``JAX_COMPILATION_CACHE_DIR``
+    set from outside reaches each of them unchanged, and the in-checkout
+    default stays keyed by each process's own device count.
     """
     env = os.environ if env is None else env
-    devices = int(env.get("XLA_DEVICES") or 1)
-    return [
-        f'export XLA_FLAGS="{device_flags(devices, env.get("XLA_FLAGS"))}"',
-        f'export JAX_COMPILATION_CACHE_DIR="{cache_dir(devices, env)}"',
-    ]
+    flags = device_flags(host_devices(env), env.get("XLA_FLAGS"))
+    return [f'export XLA_FLAGS="{flags}"']
 
 
 def main(argv: list[str] | None = None) -> int:

@@ -468,18 +468,39 @@ SERVE_HIST_BINS = 16
 N_BW_REGIONS = 5
 
 
+def fixed_order_sum(x):
+    """Sum of ``x`` over its last axis, added in one fixed pairwise order.
+
+    ``x.sum()`` leaves the order of the additions to the compiler, and the
+    TPU compiler chooses it per compiled batch width: a vmapped sum of
+    fractional floats then rounds differently at another ``chunk_size`` or
+    device count. Halving the zero-padded axis with elementwise adds pins
+    the order, so each row's sum depends on that row alone.
+    """
+    n = x.shape[-1]
+    width = 1 << max(n - 1, 0).bit_length()
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - n)])
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x[..., 0]
+
+
 def zipf_weights(obj_idx, zipf_alpha, n_objects, xp=jnp):
     """Zipf(α) popularity weights over objects, normalized to sum 1.
 
     ``obj_idx`` ranks objects by popularity (0 = hottest, weight
     ``(i+1)^-α``); indices ≥ ``n_objects`` (grid padding) get weight 0 and
     the rest renormalize over the active objects only.  ``zipf_alpha = 0``
-    degenerates to uniform popularity.
+    degenerates to uniform popularity. The ``jnp`` path normalizes with
+    :func:`fixed_order_sum`, so the engine's weights do not depend on its
+    batch width.
     """
     rank = xp.asarray(obj_idx, dtype=xp.float32) + 1.0
     w = rank ** -xp.asarray(zipf_alpha, dtype=xp.float32)
     w = xp.where(obj_idx < n_objects, w, 0.0)
-    return w / xp.maximum(w.sum(), 1e-30)
+    total = fixed_order_sum(w) if xp is jnp else w.sum()
+    return w / xp.maximum(total, 1e-30)
 
 
 def congestion_factor(load_units, region_cap, xp=jnp):

@@ -94,13 +94,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.core import policies as P
 from repro.core.samplers import SAMPLERS, Sampler
-# version-compat shard_map (jax<0.6 experimental location, check_rep vs
-# check_vma kwarg) — one shim, shared with the distributed substrate
-from repro.distributed.compression import shard_map
 
 # Policy ids re-exported from the shared definitions (repro.core.policies)
 # so existing `scenarios.CHURN_*` / `scenarios.ADV_*` callers keep working.
@@ -585,10 +583,12 @@ def _vault_serve(st: _Static, sc: Scenario, inv: _Inv, rep_state, warm,
              & (n_warm >= sc.k_outer))  # all K_outer pulls can be cache pulls
     miss_o = obj_active & ~failed_o & ~degr_o & ~hit_o
 
-    n_fail = (load * failed_o).sum()
-    n_degr = (load * degr_o).sum()
-    n_hit = (load * hit_o).sum()
-    n_miss = (load * miss_o).sum()
+    # fractional loads: a fixed addition order keeps these sums, and so the
+    # serving fields, bit-identical at any chunk_size or device count
+    n_fail = P.fixed_order_sum(load * failed_o)
+    n_degr = P.fixed_order_sum(load * degr_o)
+    n_hit = P.fixed_order_sum(load * hit_o)
+    n_miss = P.fixed_order_sum(load * miss_o)
     served_add = n_hit + n_miss + n_degr  # completed reads × 1 object unit
 
     # serving and repair compete for the same per-region links
@@ -674,7 +674,10 @@ def _compile_runner(run, devices: int = 1):
     difference is that batch-global ``.any()`` cond predicates become
     per-shard — and every such cond selects between branches that are
     arithmetically identical by construction (the conds exist purely to
-    skip work; see ``_vault_repair``'s docstring). Locked down by
+    skip work; see ``_vault_repair``'s docstring). Sums of fractional
+    floats are added in a fixed order (:func:`policies.fixed_order_sum`),
+    since a TPU compiler orders a plain reduction per batch width; every
+    other sum adds integer-valued floats, exact in any order. Locked down by
     ``scripts/smoke_devices.py`` and the subprocess tests in
     ``tests/test_scenarios.py`` / ``tests/test_samplers.py``.
 

@@ -19,26 +19,10 @@ multi-replica integration tests and the elastic-training example.
 """
 from __future__ import annotations
 
-import functools
-import inspect
-
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # jax < 0.6 keeps shard_map in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = inspect.signature(_shard_map).parameters
-
-
-def shard_map(*args, **kwargs):
-    """shard_map with kwarg compat: jax >= 0.6 renamed check_rep->check_vma."""
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map(*args, **kwargs)
 
 
 def quantize_int8(x):

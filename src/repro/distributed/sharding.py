@@ -120,18 +120,10 @@ def _axis_sizes(mesh) -> dict[str, int]:
 
 
 def abstract_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...]):
-    """Version-compat AbstractMesh constructor.
-
-    jax >= 0.5 takes ``AbstractMesh(axis_sizes, axis_names)``; jax 0.4.x
-    takes a single ``shape_tuple`` of ``(name, size)`` pairs. Accepts the
-    new-style arguments and translates when running on the old signature.
-    """
+    """A device-free mesh of the given shape, for resolving specs."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def resolve_spec(

@@ -42,14 +42,17 @@ def _gfmul_tile(a, b):
 
 def _encode_kernel(c_ref, d_ref, o_ref, *, k_dim: int):
     c = c_ref[...]  # (TR, K) int32
-    d = d_ref[...]  # (K, TL) int32
+    lanes = jax.lax.broadcasted_iota(jnp.int32, c.shape, 1)
 
     def body(k, acc):
-        a = jax.lax.dynamic_slice(c, (0, k), (c.shape[0], 1))  # (TR, 1)
-        b = jax.lax.dynamic_slice(d, (k, 0), (1, d.shape[1]))  # (1, TL)
+        # Mosaic has no dynamic_slice: pick column k of the small
+        # coefficient tile by an iota mask (exactly one lane survives the
+        # sum) and row k of the payload tile by a dynamic sublane load.
+        a = jnp.sum(jnp.where(lanes == k, c, 0), 1, keepdims=True)  # (TR, 1)
+        b = d_ref[pl.ds(k, 1), :]  # (1, TL)
         return acc ^ _gfmul_tile(a, b)
 
-    acc = jnp.zeros((c.shape[0], d.shape[1]), jnp.int32)
+    acc = jnp.zeros(o_ref.shape, jnp.int32)
     o_ref[...] = jax.lax.fori_loop(0, k_dim, body, acc)
 
 

@@ -278,10 +278,16 @@ def _solve_kernel(a_ref, y_ref, x_ref, st_ref, *, k: int):
     y = y_ref[0]                     # (mp, Lp) int32
     mp = a.shape[0]
     rows = jax.lax.broadcasted_iota(jnp.int32, (mp, 1), 0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, a.shape[1]), 1)
+
+    def column(m, col):
+        # column ``col`` of ``m`` as (rows, 1): an iota-mask pick (one lane
+        # survives the sum), since Mosaic cannot lower dynamic_slice
+        return jnp.sum(jnp.where(lanes == col, m, 0), 1, keepdims=True)
 
     def body(col, carry):
         a, y, ok, fail = carry
-        colv = jax.lax.dynamic_slice(a, (0, col), (mp, 1))
+        colv = column(a, col)
         elig = (rows >= col) & (colv != 0)
         has = jnp.any(elig)
         fail = jnp.where(ok & ~has, col, fail)
@@ -299,14 +305,13 @@ def _solve_kernel(a_ref, y_ref, x_ref, st_ref, *, k: int):
         y = jnp.where(is_piv, col_y, jnp.where(is_col, piv_y, y))
         # normalize the pivot row (failed lanes continue on garbage; the
         # sticky ok flag gates the result)
-        inv = _gfinv(jax.lax.dynamic_slice(piv_a, (0, col), (1, 1)))
+        inv = _gfinv(column(piv_a, col))
         norm_a = _gfmul(piv_a, inv)
         norm_y = _gfmul(piv_y, inv)
         a = jnp.where(is_col, norm_a, a)
         y = jnp.where(is_col, norm_y, y)
         # eliminate the column everywhere else (Gauss-Jordan)
-        f = jnp.where(is_col, 0,
-                      jax.lax.dynamic_slice(a, (0, col), (mp, 1)))
+        f = jnp.where(is_col, 0, column(a, col))
         a = a ^ _gfmul(f, norm_a)
         y = y ^ _gfmul(f, norm_y)
         return a, y, ok, fail
@@ -314,8 +319,8 @@ def _solve_kernel(a_ref, y_ref, x_ref, st_ref, *, k: int):
     a, y, ok, fail = jax.lax.fori_loop(
         0, k, body, (a, y, jnp.bool_(True), jnp.int32(-1)))
     x_ref[...] = y[:x_ref.shape[1]][None]
-    st_ref[...] = jnp.full((1, st_ref.shape[1]),
-                           jnp.where(ok, jnp.int32(-1), fail), jnp.int32)
+    st_ref[...] = jnp.full(st_ref.shape, jnp.where(ok, jnp.int32(-1), fail),
+                           jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -323,12 +328,14 @@ def gf256_solve_kernel(
     a: jax.Array, y: jax.Array, k: int, interpret: bool | None = None
 ) -> tuple[jax.Array, jax.Array]:
     """a (B, mp, kp) int32, y (B, mp, Lp) int32 -> (x (B, kp8, Lp), status
-    (B, 128)) with ``status[b, 0] == -1`` iff full rank, else the first
-    rank-deficient column. Grid = batch; each program reduces one system
-    entirely in VMEM (the systems are k ~ tens wide — far under tile
-    budgets). Padding contract (``gf256_solve_batch`` arranges it): pad
-    rows/columns are zero, so they are never eligible pivots and pass
-    through elimination unchanged.
+    (B, 1, 128)) with ``status[b, 0, 0] == -1`` iff full rank, else the
+    first rank-deficient column. Grid = batch; each program reduces one
+    system entirely in VMEM (the systems are k ~ tens wide — far under tile
+    budgets). The status block spans the array's last two dims, which the
+    TPU's (8, 128) tiling rule requires of a one-row block. Padding
+    contract (``gf256_solve_batch`` arranges it): pad rows/columns are
+    zero, so they are never eligible pivots and pass through elimination
+    unchanged.
     """
     B, mp, kp = a.shape
     _, _, lp = y.shape
@@ -343,11 +350,11 @@ def gf256_solve_kernel(
         ],
         out_specs=[
             pl.BlockSpec((1, kp8, lp), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, 128), lambda b: (b, 0)),
+            pl.BlockSpec((1, 1, 128), lambda b: (b, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, kp8, lp), jnp.int32),
-            jax.ShapeDtypeStruct((B, 128), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, 128), jnp.int32),
         ],
         interpret=interpret,
     )(a, y)
@@ -386,6 +393,6 @@ def gf256_solve_batch(
     y[:, :m, :L] = symbols
     x, st = gf256_solve_kernel(jnp.asarray(a), jnp.asarray(y), k=k,
                                interpret=interpret)
-    fail_col = np.asarray(st)[:, 0].astype(np.int32)
+    fail_col = np.asarray(st)[:, 0, 0].astype(np.int32)
     ok = fail_col < 0
     return (np.asarray(x)[:, :k, :L].astype(np.uint8), ok, fail_col)

@@ -21,14 +21,16 @@ DEFAULT_TILE_W = 512
 
 def _xor_kernel(m_ref, d_ref, o_ref, *, k_dim: int):
     m = m_ref[...]  # (TR, K) int32 in {0,1}
-    d = d_ref[...]  # (K, TW) int32
+    lanes = jax.lax.broadcasted_iota(jnp.int32, m.shape, 1)
 
     def body(k, acc):
-        sel = jax.lax.dynamic_slice(m, (0, k), (m.shape[0], 1))  # (TR, 1)
-        row = jax.lax.dynamic_slice(d, (k, 0), (1, d.shape[1]))  # (1, TW)
+        # iota-mask column pick + dynamic sublane row load, as in
+        # gf256_encode (Mosaic cannot lower dynamic_slice)
+        sel = jnp.sum(jnp.where(lanes == k, m, 0), 1, keepdims=True)  # (TR, 1)
+        row = d_ref[pl.ds(k, 1), :]  # (1, TW)
         return acc ^ jnp.where(sel != 0, row, 0)
 
-    acc = jnp.zeros((m.shape[0], d.shape[1]), jnp.int32)
+    acc = jnp.zeros(o_ref.shape, jnp.int32)
     o_ref[...] = jax.lax.fori_loop(0, k_dim, body, acc)
 
 

@@ -265,7 +265,7 @@ def analyze_depth(cfg, shape, mesh, rules) -> dict:
 def run_cell(
     arch: str, shape: str, mesh_kind: str, rules=None, write: bool = True,
     tag: str = "", cfg_override=None, analyze: bool = True,
-    compile_full: bool = True,
+    compile_full: bool = True, out_dir: pathlib.Path = RESULTS,
 ) -> dict:
     t_start = time.time()
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
@@ -322,9 +322,9 @@ def run_cell(
         rec["traceback"] = traceback.format_exc()[-4000:]
     rec["wall_s"] = round(time.time() - t_start, 2)
     if write:
-        RESULTS.mkdir(parents=True, exist_ok=True)
+        out_dir.mkdir(parents=True, exist_ok=True)
         suffix = f"__{tag}" if tag else ""
-        path = RESULTS / f"{arch}__{shape}__{mesh_kind}{suffix}.json"
+        path = out_dir / f"{arch}__{shape}__{mesh_kind}{suffix}.json"
         path.write_text(json.dumps(rec, indent=2, default=str))
     return rec
 
@@ -345,13 +345,15 @@ def main() -> int:
                     help="override the tuned per-arch rule selection")
     ap.add_argument("--tag", default="",
                     help="suffix for the result JSON (perf experiments)")
+    ap.add_argument("--out-dir", type=pathlib.Path, default=RESULTS,
+                    help="directory for the result JSONs")
     args = ap.parse_args()
     cells = configs.cells() if args.all else [(args.arch, args.shape)]
     meshes = ("single", "multi") if args.mesh == "both" else (args.mesh,)
     failures = 0
     for arch, shape in cells:
         for mesh_kind in meshes:
-            path = RESULTS / f"{arch}__{shape}__{mesh_kind}.json"
+            path = args.out_dir / f"{arch}__{shape}__{mesh_kind}.json"
             if args.skip_existing and path.exists():
                 if json.loads(path.read_text()).get("ok"):
                     print(f"[skip] {arch} {shape} {mesh_kind}", flush=True)
@@ -361,7 +363,8 @@ def main() -> int:
                            rules=shd.RULE_SETS[rule_name],
                            tag=args.tag,
                            analyze=not args.no_analysis,
-                           compile_full=not args.no_full_compile)
+                           compile_full=not args.no_full_compile,
+                           out_dir=args.out_dir)
             if rec["ok"]:
                 r = rec.get("roofline", {})
                 print(

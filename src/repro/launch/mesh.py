@@ -7,6 +7,7 @@ never touches jax device state — smoke tests see 1 device; only
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,11 +16,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     parallelism: model axes never cross the inter-pod (DCI) boundary."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return jax.make_mesh(shape, axes)
+    """A mesh with Auto axes: the harness places arrays by
+    ``with_sharding_constraint`` (``distributed.sharding.constrain``), which
+    ``jax.make_mesh``'s default Explicit axes refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_devices(mesh) -> int:

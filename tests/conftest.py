@@ -10,6 +10,10 @@ sys.path.insert(0, str(SRC))
 
 from repro import config as CFG  # noqa: E402
 
+# this process's persistent compile cache: JAX_COMPILATION_CACHE_DIR as set,
+# else <checkout>/.jax_cache/d<N> (children get theirs from subprocess_env)
+CFG.apply_cache(CFG.host_devices())
+
 
 def run_py(code: str, devices: int = 1, timeout: int = 300,
            cache_dir: str | None = None) -> str:
@@ -18,8 +22,7 @@ def run_py(code: str, devices: int = 1, timeout: int = 300,
     Used by tests that need >1 device: the main pytest process must keep
     the default single-device jax (smoke tests measure that world), so
     multi-device checks fork with XLA_FLAGS set pre-init. The environment
-    (device-count flag + topology-keyed compilation-cache dir — entries
-    are not portable across host topologies) comes from repro.config;
+    (device-count flag + compilation-cache dir) comes from repro.config;
     ``cache_dir`` overrides the compilation-cache location for tests that
     need a controlled cold/warm cache (e.g. the donation-replay
     regression).

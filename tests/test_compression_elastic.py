@@ -20,7 +20,7 @@ def test_int8_allreduce_matches_mean(subproc):
     out = subproc(
         """
 import jax, jax.numpy as jnp, numpy as np
-from repro.distributed.compression import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.distributed.compression import int8_all_reduce_mean
 mesh = jax.make_mesh((4,), ("data",))
@@ -46,7 +46,7 @@ def test_error_feedback_convergence(subproc):
     out = subproc(
         """
 import jax, jax.numpy as jnp, numpy as np
-from repro.distributed.compression import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.distributed.compression import tree_int8_all_reduce_mean
 mesh = jax.make_mesh((4,), ("data",))

@@ -1,12 +1,13 @@
 """Unit tests for repro.config — the one environment-setup path.
 
 These cover the pure string/env plumbing (no jax import, no subprocess):
-device-flag rewriting, topology-keyed cache dirs and their re-keying,
-subprocess environments and the shell-export CLI contract that
-``scripts/test.sh`` evals.
+device-flag rewriting, the compile-cache placement rules (an outside
+directory exactly, else a fixed in-checkout one), subprocess environments
+and the shell-export CLI contract that ``scripts/test.sh`` evals.
 """
 from __future__ import annotations
 
+import os
 import subprocess
 import sys
 
@@ -27,24 +28,31 @@ def test_device_flags_append_and_replace():
 
 
 def test_cache_dir_keyed_by_topology():
-    env = {"REPRO_JAX_CACHE_BASE": "/tmp/cc"}
-    assert CFG.cache_dir(1, env) == "/tmp/cc-d1"
-    assert CFG.cache_dir(8, env) == "/tmp/cc-d8"
+    # unset: a fixed in-checkout path, one directory per device count
+    root = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(CFG.__file__)))), ".jax_cache")
+    assert CFG.DEFAULT_CACHE_ROOT == root
+    assert CFG.cache_dir(1, {}) == os.path.join(root, "d1")
+    assert CFG.cache_dir(8, {}) == os.path.join(root, "d8")
 
 
-def test_cache_base_strips_existing_topology_suffix():
-    # re-keying an already-keyed dir must not stack suffixes
-    env = {"JAX_COMPILATION_CACHE_DIR": "/tmp/cc-d8"}
-    assert CFG.cache_base(env) == "/tmp/cc"
-    assert CFG.cache_dir(2, env) == "/tmp/cc-d2"
+def test_cache_dir_set_from_outside_used_unchanged():
+    # a directory placed from outside is used exactly: no device-count
+    # suffix is added, and none is stripped
+    for path in ("/some/dir", "/tmp/cc-d8"):
+        env = {"JAX_COMPILATION_CACHE_DIR": path}
+        assert CFG.cache_dir(1, env) == path
+        assert CFG.cache_dir(4, env) == path
 
 
 def test_subprocess_env_sets_flags_and_cache():
-    env = CFG.subprocess_env(4, {"PATH": "/bin",
-                                 "REPRO_JAX_CACHE_BASE": "/tmp/cc"})
+    env = CFG.subprocess_env(4, {"PATH": "/bin"})
     assert "--xla_force_host_platform_device_count=4" in env["XLA_FLAGS"]
-    assert env["JAX_COMPILATION_CACHE_DIR"] == "/tmp/cc-d4"
+    assert env["JAX_COMPILATION_CACHE_DIR"] == os.path.join(
+        CFG.DEFAULT_CACHE_ROOT, "d4")
     assert env["PATH"] == "/bin"
+    env = CFG.subprocess_env(4, {"JAX_COMPILATION_CACHE_DIR": "/some/dir"})
+    assert env["JAX_COMPILATION_CACHE_DIR"] == "/some/dir"
 
 
 def test_shell_exports_cli_round_trip():
@@ -53,10 +61,12 @@ def test_shell_exports_cli_round_trip():
         [sys.executable, "-m", "repro.config"],
         capture_output=True, text=True,
         env={"PATH": "/usr/bin:/bin", "XLA_DEVICES": "2",
-             "REPRO_JAX_CACHE_BASE": "/tmp/cc",
+             "JAX_COMPILATION_CACHE_DIR": "/some/dir",
              "PYTHONPATH": CFG.__file__.rsplit("/repro/", 1)[0]})
     assert out.returncode == 0, out.stderr
     lines = out.stdout.splitlines()
     assert any(l.startswith("export XLA_FLAGS=") and "=2" in l
                for l in lines)
-    assert 'export JAX_COMPILATION_CACHE_DIR="/tmp/cc-d2"' in lines
+    # the cache is placed per process (repro.config.apply_cache), never
+    # exported: a child must not inherit its parent's device-keyed default
+    assert not any("JAX_COMPILATION_CACHE_DIR" in l for l in lines)

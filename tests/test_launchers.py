@@ -37,12 +37,13 @@ def test_serve_driver():
     assert "decode:" in out and "tok/s" in out
 
 
-def test_dryrun_single_cell_fast():
+def test_dryrun_single_cell_fast(tmp_path):
     """One real dry-run cell on the 512-device mesh, analysis skipped
     (the full sweep is results/dryrun; this guards the entry point)."""
     out = run_module(
         "repro.launch.dryrun", "--arch", "mamba2-2.7b", "--shape",
         "decode_32k", "--mesh", "single", "--no-analysis", "--tag", "smoke",
-        timeout=420,
+        "--out-dir", str(tmp_path), timeout=420,
     )
     assert "[ok]" in out
+    assert (tmp_path / "mamba2-2.7b__decode_32k__single__smoke.json").exists()

@@ -126,3 +126,32 @@ def test_protocol_conservation_single_run():
     assert r.serve_hop_hist.sum() == r.reads_issued - r.reads_failed
     assert np.all(r.serve_trace[:, 0]
                   == r.serve_trace[:, 1:].sum(axis=1))
+
+
+def test_fixed_order_sum_depends_on_the_row_alone():
+    """The engine's serving sums add in one fixed pairwise order: a row's
+    sum has the same bits alone, inside a batch of any width under
+    jit(vmap), and in a numpy float32 replay of that order."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import policies as P
+
+    rng = np.random.default_rng(0)
+    rows = (rng.random((4, 1000)) * 10.0 ** rng.integers(-6, 3, (4, 1000))
+            ).astype(np.float32)
+
+    def replay(x):
+        x = np.concatenate([x, np.zeros(1024 - x.size, np.float32)])
+        while x.size > 1:
+            x = x[:x.size // 2] + x[x.size // 2:]
+        return x[0]
+
+    batched = jax.jit(jax.vmap(P.fixed_order_sum))
+    want = np.array([replay(r) for r in rows])
+    for width in (1, 2, 4):
+        got = np.concatenate([np.asarray(batched(jnp.asarray(rows[i:i + width])))
+                              for i in range(0, 4, width)])
+        np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(np.asarray(P.fixed_order_sum(rows)), want)
+    assert P.fixed_order_sum(jnp.ones(1)) == 1.0

@@ -8,8 +8,6 @@ from repro import configs
 from repro.distributed import sharding as shd
 from repro.models import cache_specs, init_cache, init_params, param_specs
 
-# shd.abstract_mesh handles both the jax>=0.5 (sizes, names) signature and
-# the 0.4.x shape_tuple signature
 MESH_SINGLE = shd.abstract_mesh((16, 16), ("data", "model"))
 MESH_MULTI = shd.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
 
@@ -109,6 +107,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh
 from repro.models import init_params, param_specs
 from repro.training import init_train_state, make_train_step
 from repro.optim import AdamWConfig
@@ -120,7 +119,7 @@ batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab, (4, 16)), jnp.int32)}
 step = make_train_step(cfg, AdamWConfig())
 _, m_single = jax.jit(step)(jax.tree_util.tree_map(jnp.copy, state), batch)
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 shapes = jax.eval_shape(lambda: state)
 resolved = shd.tree_specs(param_specs(cfg), shapes["params"], mesh)
 named = jax.tree_util.tree_map(
