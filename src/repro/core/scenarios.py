@@ -119,6 +119,24 @@ N_REGIONS = P.N_REGIONS
 
 _UNROLL = 2  # scan unroll factor (see "Performance knobs")
 
+# Profiler names. Host spans (``jax.profiler.TraceAnnotation``) tile each
+# grid call, so a device-idle gap between dispatches can be charged to what
+# the host was doing; the children of ``vault.grid`` run once per call or
+# once per chunk, never per element. Name scopes (``jax.named_scope``) tag
+# every op of the scan body with its phase in the compiled program's
+# metadata. ``perfbench/scopes.py`` reads both; ``docs/engine_guide.md``
+# ("Profiling a sweep") says what each covers.
+SPAN_GRID = "vault.grid"      # the whole public call
+SPAN_BUILD = "vault.build"    # elements, padded maxima, runner lookup
+SPAN_STACK = "vault.stack"    # one chunk's inputs stacked on the host
+SPAN_LAUNCH = "vault.launch"  # compile on first call, transfer, enqueue
+SPAN_FETCH = "vault.fetch"    # device wait, outputs copied to the host
+SPAN_GATHER = "vault.gather"  # chunks joined, padding cut, reshaped
+SCOPE_CHURN = "vault.churn"    # churn draws, burst thinning, attack
+SCOPE_REPAIR = "vault.repair"  # the repair cond
+SCOPE_SERVE = "vault.serve"    # the serve cond
+SCOPE_MERGE = "vault.merge"    # finished elements keep their state
+
 
 def _default_unroll(sampler: str) -> int:
     # unrolling doubles the traced body: worth ~2x runtime for the compact
@@ -738,33 +756,38 @@ def _vault_batch(st: _Static, sampler: str, unroll: int = _UNROLL,
         serve_any = (scb.read_rate > 0.0).any()
 
         def body(state, t):
-            h, b, burst, region, kx, kr, ka = churn(scb, inv, state, t)
-            h, b = jax.lax.cond(
-                burst.any(),
-                lambda args: burst_thin(scb, inv, *args),
-                lambda args: (args[0], args[1]),
-                (h, b, burst, region, kx))
-            hit_now = P.targeted_flag(scb.adv_policy) & (t == scb.attack_step)
-            h = jax.lax.cond(
-                hit_now.any(),
-                lambda args: jnp.where(hit_now[:, None],
-                                       attack(scb, *args), args[0]),
-                lambda args: args[0], (h, state[2], ka))
-            rep_state, warm, traffic_add, alive_frac = jax.lax.cond(
-                cache_any,
-                lambda args: repair_cache(*args),
-                lambda args: repair_plain(*args),
-                (scb, inv, state, h, b, kr, t))
-            srv = jax.lax.cond(
-                serve_any,
-                lambda args: serve(*args),
-                lambda args: args[5],
-                (scb, inv, rep_state, warm, traffic_add, state[10:], t))
-            on = t < scb.steps
-            state = tuple(_where_on(on, n, o)
-                          for n, o in zip(rep_state + srv, state))
-            return state, jnp.where(on, alive_frac,
-                                    state[2].sum(-1) / inv.n_groups)
+            with jax.named_scope(SCOPE_CHURN):
+                h, b, burst, region, kx, kr, ka = churn(scb, inv, state, t)
+                h, b = jax.lax.cond(
+                    burst.any(),
+                    lambda args: burst_thin(scb, inv, *args),
+                    lambda args: (args[0], args[1]),
+                    (h, b, burst, region, kx))
+                hit_now = (P.targeted_flag(scb.adv_policy)
+                           & (t == scb.attack_step))
+                h = jax.lax.cond(
+                    hit_now.any(),
+                    lambda args: jnp.where(hit_now[:, None],
+                                           attack(scb, *args), args[0]),
+                    lambda args: args[0], (h, state[2], ka))
+            with jax.named_scope(SCOPE_REPAIR):
+                rep_state, warm, traffic_add, alive_frac = jax.lax.cond(
+                    cache_any,
+                    lambda args: repair_cache(*args),
+                    lambda args: repair_plain(*args),
+                    (scb, inv, state, h, b, kr, t))
+            with jax.named_scope(SCOPE_SERVE):
+                srv = jax.lax.cond(
+                    serve_any,
+                    lambda args: serve(*args),
+                    lambda args: args[5],
+                    (scb, inv, rep_state, warm, traffic_add, state[10:], t))
+            with jax.named_scope(SCOPE_MERGE):
+                on = t < scb.steps
+                state = tuple(_where_on(on, n, o)
+                              for n, o in zip(rep_state + srv, state))
+                return state, jnp.where(on, alive_frac,
+                                        state[2].sum(-1) / inv.n_groups)
 
         state, alive_tr = jax.lax.scan(body, init, jnp.arange(st.max_steps),
                                        unroll=unroll)
@@ -788,11 +811,6 @@ def _product(cells, seeds) -> list[Scenario]:
     return out
 
 
-def _reshape(res, n_cells: int, n_seeds: int):
-    return type(res)(*(np.asarray(x).reshape(n_cells, n_seeds, *x.shape[1:])
-                       for x in res))
-
-
 def _dispatch(runner, batch):
     """Invoke a compiled runner (single indirection point for all four
     grid runners — kept so chunked and single dispatch share one call
@@ -802,37 +820,56 @@ def _dispatch(runner, batch):
     return runner(batch)
 
 
-def _run_chunked(flat: list[Scenario], runner, chunk_size: int | None,
-                 devices: int | None = None):
-    """Dispatch ``flat`` elements through ``runner`` in fixed-size chunks.
+def _run_chunked(flat: list, runner, chunk_size: int | None,
+                 devices: int, n_seeds: int):
+    """Dispatch ``flat`` elements through ``runner`` in fixed-size chunks
+    and return host arrays of shape ``[len(flat) // n_seeds, n_seeds, ...]``.
 
-    ``chunk_size=None`` keeps the single-dispatch fast path. Otherwise the
-    element list is padded (with replicas of the last element, sliced off
-    afterwards) to a multiple of ``chunk_size`` and dispatched chunk by
-    chunk — every chunk has identical shapes, so jit compiles exactly once.
-    ``runner`` is already topology-bound (see :func:`_compile_runner`);
-    with ``devices > 1`` the chunk size is rounded up to a multiple of the
-    device count so ``shard_map`` can split the batch axis evenly — uneven
-    batches are handled entirely by the same padding path. Chunking and
-    sharding are bit-for-bit neutral: element randomness depends only on
-    the element itself, never on its batch position.
+    ``chunk_size=None`` (or one at least the batch) is one dispatch.
+    Otherwise the element list is padded (with replicas of the last
+    element, sliced off afterwards) to a multiple of ``chunk_size`` and
+    dispatched chunk by chunk — every chunk has identical shapes, so jit
+    compiles exactly once. ``runner`` is already topology-bound (see
+    :func:`_compile_runner`); with ``devices > 1`` the chunk size is
+    rounded up to a multiple of the device count so ``shard_map`` can split
+    the batch axis evenly — uneven batches are handled entirely by the same
+    padding path. Chunking and sharding are bit-for-bit neutral: element
+    randomness depends only on the element itself, never on its batch
+    position. Each chunk's outputs reach the host inside ``vault.fetch``.
     """
     B = len(flat)
-    ndev = int(devices or 1)
-    if ndev > 1:
-        chunk_size = min(chunk_size or B, B)
-        chunk_size = -(-chunk_size // ndev) * ndev
-    elif not chunk_size or chunk_size >= B:
-        return _dispatch(runner, _stack(flat))
-    pad = (-B) % chunk_size
-    padded = list(flat) + [flat[-1]] * pad
+    chunk_size = min(chunk_size or B, B)
+    chunk_size = -(-chunk_size // devices) * devices
+    padded = list(flat) + [flat[-1]] * ((-B) % chunk_size)
     outs = []
     for i in range(0, len(padded), chunk_size):
-        out = _dispatch(runner, _stack(padded[i:i + chunk_size]))
-        outs.append(jax.tree_util.tree_map(np.asarray, out))
-    cat = jax.tree_util.tree_map(
-        lambda *xs: np.concatenate(xs, axis=0), *outs)
-    return jax.tree_util.tree_map(lambda x: x[:B], cat)
+        with jax.profiler.TraceAnnotation(SPAN_STACK):
+            batch = _stack(padded[i:i + chunk_size])
+        with jax.profiler.TraceAnnotation(SPAN_LAUNCH):
+            out = _dispatch(runner, batch)
+        with jax.profiler.TraceAnnotation(SPAN_FETCH):
+            # rebinding ``out`` frees the device buffers inside this span
+            out = jax.tree_util.tree_map(np.asarray, out)
+            outs.append(out)
+    with jax.profiler.TraceAnnotation(SPAN_GATHER):
+        cat = outs[0] if len(outs) == 1 else jax.tree_util.tree_map(
+            lambda *xs: np.concatenate(xs, axis=0), *outs)
+        return jax.tree_util.tree_map(
+            lambda x: x[:B].reshape(B // n_seeds, n_seeds, *x.shape[1:]), cat)
+
+
+def _grid(cells, seeds, chunk_size: int | None, devices: int | None,
+          build):
+    """The one path of the four grid runners: ``build(flat, ndev)`` returns
+    the compiled runner and the elements it takes (``flat``, the
+    ``cells x seeds`` scenarios, cell-major, or values derived from them);
+    the result's leaves are ``[n_cells, n_seeds, ...]`` host arrays."""
+    with jax.profiler.TraceAnnotation(SPAN_GRID):
+        with jax.profiler.TraceAnnotation(SPAN_BUILD):
+            seeds = list(seeds)
+            ndev = _ndev(devices)
+            runner, elements = build(_product(cells, seeds), ndev)
+        return _run_chunked(elements, runner, chunk_size, ndev, len(seeds))
 
 
 def run_grid(cells, seeds=range(8), sampler: str = "exact",
@@ -846,18 +883,17 @@ def run_grid(cells, seeds=range(8), sampler: str = "exact",
     have shape ``[n_cells, n_seeds]`` (the trace leaf
     ``[n_cells, n_seeds, max_steps]``).
     """
-    seeds = list(seeds)
     unroll = _default_unroll(sampler) if unroll is None else unroll
-    ndev = _ndev(devices)
-    flat = _product(cells, seeds)
-    st = _Static(
-        max_groups=max(int(s.n_objects * s.n_chunks) for s in flat),
-        max_objects=max(int(s.n_objects) for s in flat),
-        max_steps=max(int(s.steps) for s in flat),
-    )
-    res = _run_chunked(flat, _vault_batch(st, sampler, unroll, ndev),
-                       chunk_size, ndev)
-    return _reshape(res, len(flat) // len(seeds), len(seeds))
+
+    def build(flat, ndev):
+        st = _Static(
+            max_groups=max(int(s.n_objects * s.n_chunks) for s in flat),
+            max_objects=max(int(s.n_objects) for s in flat),
+            max_steps=max(int(s.steps) for s in flat),
+        )
+        return _vault_batch(st, sampler, unroll, ndev), flat
+
+    return _grid(cells, seeds, chunk_size, devices, build)
 
 
 # ------------------------------------------------------ replicated baseline
@@ -977,16 +1013,13 @@ def run_replicated_grid(cells, seeds=range(8), sampler: str = "exact",
                         chunk_size: int | None = None,
                         devices: int | None = None) -> ScenarioResult:
     """Ceph-like replicated baseline, same grid semantics as run_grid."""
-    seeds = list(seeds)
-    ndev = _ndev(devices)
-    flat = _product(cells, seeds)
-    st = _Static(max_groups=1,
-                 max_objects=max(int(s.n_objects) for s in flat),
-                 max_steps=max(int(s.steps) for s in flat))
-    unroll = _default_unroll(sampler)
-    res = _run_chunked(flat, _repl_batch(st, sampler, unroll, ndev),
-                       chunk_size, ndev)
-    return _reshape(res, len(flat) // len(seeds), len(seeds))
+    def build(flat, ndev):
+        st = _Static(max_groups=1,
+                     max_objects=max(int(s.n_objects) for s in flat),
+                     max_steps=max(int(s.steps) for s in flat))
+        return _repl_batch(st, sampler, _default_unroll(sampler), ndev), flat
+
+    return _grid(cells, seeds, chunk_size, devices, build)
 
 
 # --------------------------------------------------------- Fig 5 trace grid
@@ -1046,18 +1079,16 @@ def trace_grid(cells, seeds=range(8), repair_interval_hours: float = 24.0,
     cells × seeds. Returns ``[n_cells, n_seeds, max_steps]`` int64; cells
     with a shorter horizon than the padded maximum hold their last value
     for the remaining steps."""
-    seeds = list(seeds)
-    ndev = _ndev(devices)
-    flat = _product(cells, seeds)
-    max_steps = max(int(s.steps) for s in flat)
-    runner = _trace_batch(max_steps, sampler, ndev)
-    # _run_chunked stacks element lists as pytrees; pair each scenario with
-    # its repair interval so the same chunking path applies.
-    interval = np.float32(repair_interval_hours)
-    paired = [(interval, s) for s in flat]
-    out = _run_chunked(paired, runner, chunk_size, ndev)
-    return np.asarray(out, np.int64).reshape(
-        len(flat) // len(seeds), len(seeds), max_steps)
+    def build(flat, ndev):
+        max_steps = max(int(s.steps) for s in flat)
+        # _run_chunked stacks element lists as pytrees; pair each scenario
+        # with its repair interval so the same chunking path applies.
+        interval = np.float32(repair_interval_hours)
+        return (_trace_batch(max_steps, sampler, ndev),
+                [(interval, s) for s in flat])
+
+    out = _grid(cells, seeds, chunk_size, devices, build)
+    return out.astype(np.int64)
 
 
 # --------------------------------------------------- Fig 6 targeted attacks
@@ -1093,15 +1124,13 @@ def targeted_grid(cells, seeds=range(8), sampler: str = "exact",
                   devices: int | None = None) -> np.ndarray:
     """Lost-object fraction under the greedy targeted attack (Fig. 6
     bottom), batched over cells × seeds: ``[n_cells, n_seeds]`` float."""
-    seeds = list(seeds)
-    ndev = _ndev(devices)
-    flat = _product(cells, seeds)
-    st = _Static(
-        max_groups=max(int(s.n_objects * s.n_chunks) for s in flat),
-        max_objects=max(int(s.n_objects) for s in flat), max_steps=1)
-    out = _run_chunked(flat, _targeted_batch(st, sampler, ndev),
-                       chunk_size, ndev)
-    return np.asarray(out).reshape(len(flat) // len(seeds), len(seeds))
+    def build(flat, ndev):
+        st = _Static(
+            max_groups=max(int(s.n_objects * s.n_chunks) for s in flat),
+            max_objects=max(int(s.n_objects) for s in flat), max_steps=1)
+        return _targeted_batch(st, sampler, ndev), flat
+
+    return _grid(cells, seeds, chunk_size, devices, build)
 
 
 # ------------------------------------------------------------- summarizing
