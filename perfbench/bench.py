@@ -9,7 +9,9 @@ sits in a file of its own under ``perfbench/``, found by the name that
   ``runners/<runner>.py`` and ``references/<reference>.py``;
 * ``traffic/<traffic>.json``: the traffic mix and the dispatch layout;
 * ``checks/<workload>.json``: the numbers compared and their limits;
-* ``metrics/<metric>.py``: a reader ``read(run) -> float | None``.
+* ``metrics/<metric>.py``: a reader ``read(run) -> float | None``, run in
+  the cells that the metric's ``workloads`` list names, or in every cell
+  where it has none.
 """
 from __future__ import annotations
 
@@ -27,9 +29,12 @@ import jax.monitoring
 import numpy as np
 
 from perfbench import check as C
+from perfbench import scopes as S
 from perfbench import trace as T
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# keys the persistent compile cache with op metadata (a traced set-up)
+METADATA_KEY = "jax_compilation_cache_include_metadata_in_key"
 # seed streams derived from the run's --seed (check.seeds_for)
 WARM, WINDOW, REFERENCE, CONTROL = 0, 1, 2, 4
 # a traced window runs this many dispatches (two gaps between them): a trace
@@ -76,11 +81,14 @@ class Bench:
     def peaks(self) -> dict:
         return self._json("peaks.json")
 
-    def metrics(self, traced: bool) -> list:
-        """The metrics every cell reports: the per-layer ones when traced,
-        else the end-to-end ones. A reader that finds nothing to read in a
-        cell returns ``None`` and its metric is left out of the line."""
-        return self.spec["per_layer" if traced else "end_to_end"]
+    def metrics(self, traced: bool, workload: str) -> list:
+        """The metrics the cell ``workload`` reports: the per-layer ones
+        when traced, else the end-to-end ones; a metric with a
+        ``workloads`` list only in the cells it lists. A reader that finds
+        nothing to read in a cell returns ``None`` and its metric is left
+        out of the line."""
+        return [m for m in self.spec["per_layer" if traced else "end_to_end"]
+                if workload in m.get("workloads", [workload])]
 
     def module(self, kind: str, name: str):
         """``perfbench/<kind>/<name>.py``, loaded by path."""
@@ -160,7 +168,8 @@ class Run:
     state_bytes_per_step: float   # least bytes one step moves, all chips
     peaks: dict                   # this device's row of peaks.json
     program_prefix: str           # name of the dispatches' compiled program
-    trace: T.Trace | None = None
+    trace: T.Trace | None = None  # a scopes.ScopedTrace when traced
+    counters: dict | None = None  # the runner's counters() over the window
 
 
 @dataclasses.dataclass
@@ -172,12 +181,19 @@ class Cell:
     program_prefix: str
     build_s: float                # building the runner and its inputs
     warm_s: float                 # the warm-up dispatch: compile or cache load
+    hlo: str = ""                 # the program's HLO text (traced set-up)
 
 
-def set_up(bench: Bench, workload: str, seed: int) -> Cell:
+def set_up(bench: Bench, workload: str, seed: int,
+           traced: bool = False) -> Cell:
     """Build the cell's runner and run one dispatch of exactly one chunk,
     which compiles (or loads from the cache) the one program the window
-    runs."""
+    runs. Traced, a runner that offers ``program_hlo(call)`` runs the
+    warm-up through it and hands over the compiled program's HLO text,
+    which names each operation's scope. The compile cache is then keyed
+    with op metadata, so the program that runs is built from this
+    version's HLO, not loaded from a build of another version (the same
+    ops, other scopes)."""
     t = time.perf_counter()
     wl = bench.workload(workload)
     cfg = bench.config(wl["config"])
@@ -189,11 +205,21 @@ def set_up(bench: Bench, workload: str, seed: int) -> Cell:
     runner_mod = bench.module("runners", cfg["runner"])
     runner = runner_mod.Runner(cfg, traffic)
     built = time.perf_counter()
-    runner.dispatch(runner.plan(0),
-                    C.seeds_for(seed, WARM, 0, runner.seeds_per_dispatch))
+    seeds = C.seeds_for(seed, WARM, 0, runner.seeds_per_dispatch)
+    hook = getattr(runner, "program_hlo", None) if traced else None
+    hlo = ""
+    if hook is None:
+        runner.dispatch(runner.plan(0), seeds)
+    else:
+        keyed = getattr(jax.config, METADATA_KEY)
+        jax.config.update(METADATA_KEY, True)
+        try:
+            hlo = hook(lambda: runner.dispatch(runner.plan(0), seeds))
+        finally:
+            jax.config.update(METADATA_KEY, keyed)
     return Cell(runner, bench.module("references", cfg["reference"]),
                 runner_mod.PROGRAM_PREFIX, built - t,
-                time.perf_counter() - built)
+                time.perf_counter() - built, hlo)
 
 
 def host_times() -> tuple:
@@ -226,19 +252,26 @@ class Window:
     state_bytes: float            # least bytes per step of the last dispatch
     host: tuple                   # host_times() over the window
     trace: T.Trace | None = None
+    counters: dict | None = None
 
 
 def measure(runner, seed: int, seconds: float, traced: bool,
-            min_dispatches: int = 1) -> Window:
+            min_dispatches: int = 1, hlo: str = "") -> Window:
     """Back-to-back dispatches until the one in flight at ``seconds`` returns
-    and ``min_dispatches`` have run; with ``traced``, under the profiler."""
+    and ``min_dispatches`` have run; with ``traced``, under the profiler,
+    the trace reduced with the engine's spans and, from ``hlo``, its
+    scopes. A runner that offers ``counters()`` (``{name: number}``) is
+    read at the window's start and end, and the window keeps the
+    difference."""
     elements, hours, rep, state = [], 0.0, 0, 0
+    counters = getattr(runner, "counters", None)
     trace_dir = tempfile.mkdtemp(prefix="perfbench-trace-") if traced else None
     try:
         if traced:
             jax.profiler.start_trace(trace_dir)
         try:
             with jax.profiler.TraceAnnotation(T.WINDOW_SPAN):
+                counted = counters() if counters else None
                 host = host_times()
                 start = time.perf_counter()
                 while True:
@@ -259,6 +292,9 @@ def measure(runner, seed: int, seconds: float, traced: bool,
                         break
                 took = time.perf_counter() - start
                 host = tuple(b - a for a, b in zip(host, host_times()))
+                if counters:
+                    counted = {k: v - counted.get(k, 0)
+                               for k, v in counters().items()}
         finally:
             if traced:
                 jax.profiler.stop_trace()
@@ -266,11 +302,11 @@ def measure(runner, seed: int, seconds: float, traced: bool,
         if traced:
             (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile",
                                              "*", "*.xplane.pb"))
-            trace = T.from_xspace(path)
+            trace = S.from_xspace(path, hlo)
     finally:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
-    return Window(elements, hours, rep, took, state, host, trace)
+    return Window(elements, hours, rep, took, state, host, trace, counted)
 
 
 def judge(bench: Bench, workload: str, runner, ref_mod, elements: list,
@@ -303,33 +339,51 @@ def memory_peak(devices) -> int:
     return max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
 
 
+def reading(bench: Bench, cell: Cell, win: Window, setup_s: float,
+            compiles: int, devices) -> Run:
+    """What the metric readers read about a cell's run."""
+    return Run(setup_s=setup_s, window_s=win.seconds, hours=win.hours,
+               dispatches=win.dispatches, compiles_in_window=compiles,
+               chips=len(devices), max_steps=cell.runner.max_steps,
+               state_bytes_per_step=win.state_bytes,
+               peaks=bench.peaks().get(devices[0].device_kind, {}),
+               program_prefix=cell.program_prefix, trace=win.trace,
+               counters=win.counters)
+
+
+def read_metrics(bench: Bench, workload: str, traced: bool, run: Run) -> dict:
+    """``{name: value}`` of the metrics the cell reports, by their readers;
+    a metric whose reader finds nothing to read is left out."""
+    values = {}
+    for m in bench.metrics(traced, workload):
+        value = bench.module("metrics", m["name"]).read(run)
+        if value is not None:
+            values[m["name"]] = value
+    return values
+
+
 def execute(bench: Bench, workload: str, seed: int, seconds: float,
             traced: bool, devices, t0: float) -> tuple:
     """Run one cell: set-up (counted from ``t0``), the window, the check.
     Returns the result line and the lines to print last on standard error."""
     reached = time.perf_counter() - t0
     with CompileCounter() as compiles:
-        cell = set_up(bench, workload, seed)
+        cell = set_up(bench, workload, seed, traced)
         setup_s = time.perf_counter() - t0
         compiles.open_window()
         if traced:
-            win = measure(cell.runner, seed, 0.0, True, TRACE_DISPATCHES)
+            win = measure(cell.runner, seed, 0.0, True, TRACE_DISPATCHES,
+                          cell.hlo)
         else:
             win = measure(cell.runner, seed, seconds, False)
     mem = memory_peak(devices)
     values, failed, chk = judge(bench, workload, cell.runner, cell.reference,
                                 win.elements, seed)
-    run = Run(setup_s=setup_s, window_s=win.seconds, hours=win.hours,
-              dispatches=win.dispatches, compiles_in_window=compiles.window,
-              chips=len(devices), max_steps=cell.runner.max_steps,
-              state_bytes_per_step=win.state_bytes,
-              peaks=bench.peaks().get(devices[0].device_kind, {}),
-              program_prefix=cell.program_prefix, trace=win.trace)
-    metrics = {}
-    for m in bench.metrics(traced):
-        value = bench.module("metrics", m["name"]).read(run)
-        if value is not None:
-            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    run = reading(bench, cell, win, setup_s, compiles.window, devices)
+    units = {m["name"]: m["unit"] for m in bench.metrics(traced, workload)}
+    metrics = {name: {"value": value, "unit": units[name]}
+               for name, value in read_metrics(bench, workload, traced,
+                                               run).items()}
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
               "memory_peak_bytes": mem}

@@ -3,23 +3,18 @@
 
     python3 perfbench/profile_cell.py --workload d1-serve --seed 7
 
-Sets the cell up as ``run.py`` does (with op metadata in the compile
-cache's key), taking the compiled program's HLO text from the warm-up
-dispatch, runs the window of ``run.py --trace 1`` (three
-dispatches) under the JAX profiler, and reduces the trace with
-``perfbench/scopes.py``. Results are not checked: ``run.py`` does that. The
-last line of standard output is one JSON object:
+Sets the cell up and runs its window as ``run.py --trace 1`` does (the
+compile cache keyed with op metadata, the compiled program's HLO text taken
+from the warm-up dispatch, three dispatches under the JAX profiler, the
+trace reduced with ``perfbench/scopes.py``). Results are not checked:
+``run.py`` does that. The last line of standard output is one JSON object:
 
-* ``metrics``: the per-layer metrics of ``BENCHMARK.json``, read from this
-  trace by their own readers; then, per chip, ``<phase>_step_us`` for the
-  scan body's phases (``churn``, ``repair``, ``serve``, ``merge``: device time
-  under the phase's scope over ``dispatches x max_steps``, as
-  ``scan_step_us`` divides) and ``unscoped_step_us`` (the program's device
-  time under none of them); and ``gap_prepare_ms``, ``gap_collect_ms`` and
-  ``gap_harness_ms``: the mean, over the gaps ``host_gap_ms`` averages, of
-  the device-idle time under ``vault.build`` / ``vault.stack`` /
-  ``vault.launch``, under ``vault.fetch`` / ``vault.gather``, and under no
-  ``vault.grid``. A program without the scopes or spans leaves those out.
+* ``metrics``: the cell's per-layer metrics of ``BENCHMARK.json``, read from
+  this trace by their own readers; then the whole split
+  (``scopes.split``), which adds ``unscoped_step_us`` (the program's device
+  time a step under none of the phases) and ``gap_harness_ms`` (device-idle
+  time a gap under no ``vault.grid``). A program without the scopes or
+  spans leaves those out.
 * ``window_s``, ``dispatches`` and ``dispatch_s`` (window over dispatches) of
   the traced window, on the host clock; ``span_ms``, each engine span's mean
   length;
@@ -34,12 +29,9 @@ import time
 T0 = time.perf_counter()
 
 import argparse  # noqa: E402
-import glob  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
-import shutil  # noqa: E402
 import sys  # noqa: E402
-import tempfile  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HEAD_OPS = 600  # operations kept from the start of the window
@@ -69,25 +61,6 @@ def trim(trace, prefix: str):
                        spans=trace.spans, scopes=scopes)
 
 
-def split(trace, prefix: str, dispatches: int, max_steps: int) -> dict:
-    """The phase and gap numbers this script adds to ``metrics``."""
-    from perfbench import scopes as S
-
-    out = {}
-    if trace.has_scopes:
-        steps = dispatches * max_steps
-        for phase in S.PHASES:
-            name = phase.split(".", 1)[1] + "_step_us"
-            out[name] = 1e6 * trace.scoped_busy_s((phase,)) / steps
-        rest = trace.program_busy_s(prefix) - trace.scoped_busy_s(S.PHASES)
-        out["unscoped_step_us"] = 1e6 * rest / steps
-    gaps = trace.gap_split(prefix)
-    if gaps and any(name == S.GRID for name, _, _ in trace.spans):
-        for i, part in enumerate(("prepare", "collect", "harness")):
-            out[f"gap_{part}_ms"] = 1e3 * sum(g[i] for g in gaps) / len(gaps)
-    return out
-
-
 def span_ms(trace) -> dict:
     """Mean milliseconds of each engine span in the window, by name."""
     from perfbench import scopes as S
@@ -106,8 +79,6 @@ def main(argv=None) -> int:
     ap.add_argument("--fixture", default=None)
     args = ap.parse_args(argv)
     sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
-    import jax
-
     from perfbench import bench as B
     from perfbench import scopes as S
 
@@ -117,44 +88,17 @@ def main(argv=None) -> int:
     except B.NoChip as e:
         print(f"perfbench: {e}; no result", file=sys.stderr)
         return 2
-    # The compile cache's key leaves op metadata out by default, so a cached
-    # build of another version of the program (the same ops, other scopes)
-    # could run here and hand over its metadata. Keyed with it, the program
-    # that runs is built from this version's HLO.
-    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
-    cells = []
     with B.CompileCounter() as compiles:
-        hlo = S.program_hlo(
-            lambda: cells.append(B.set_up(b, args.workload, args.seed)))
-        cell = cells[0]
+        cell = B.set_up(b, args.workload, args.seed, traced=True)
         setup_s = time.perf_counter() - T0
         compiles.open_window()
-        trace_dir = tempfile.mkdtemp(prefix="perfbench-profile-")
-        try:
-            jax.profiler.start_trace(trace_dir)
-            try:
-                win = B.measure(cell.runner, args.seed, 0.0, False,
-                                B.TRACE_DISPATCHES)
-            finally:
-                jax.profiler.stop_trace()
-            (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile",
-                                             "*", "*.xplane.pb"))
-            trace = S.from_xspace(path, hlo)
-        finally:
-            shutil.rmtree(trace_dir, ignore_errors=True)
-    run = B.Run(setup_s=setup_s, window_s=win.seconds, hours=win.hours,
-                dispatches=win.dispatches, compiles_in_window=compiles.window,
-                chips=len(devices), max_steps=cell.runner.max_steps,
-                state_bytes_per_step=win.state_bytes,
-                peaks=b.peaks().get(devices[0].device_kind, {}),
-                program_prefix=cell.program_prefix, trace=trace)
-    metrics = {}
-    for m in b.metrics(True):
-        value = b.module("metrics", m["name"]).read(run)
-        if value is not None:
-            metrics[m["name"]] = value
-    metrics.update(split(trace, cell.program_prefix, win.dispatches,
-                         cell.runner.max_steps))
+        win = B.measure(cell.runner, args.seed, 0.0, True, B.TRACE_DISPATCHES,
+                        cell.hlo)
+    trace = win.trace
+    run = B.reading(b, cell, win, setup_s, compiles.window, devices)
+    metrics = B.read_metrics(b, args.workload, True, run)
+    metrics.update(S.split(trace, cell.program_prefix, win.dispatches,
+                           cell.runner.max_steps))
     if args.fixture:
         with open(args.fixture, "w") as fh:
             fh.write(trim(trace, cell.program_prefix).to_json())

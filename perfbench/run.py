@@ -8,11 +8,12 @@ loads the cell's one program) is timed from the start of this process. The
 window then runs back-to-back dispatches until the one in flight at
 ``--seconds`` returns. ``--trace 1`` runs a window of three of the same
 dispatches under the profiler (a trace holds about a million device events a
-second on each chip) and reports the per-layer metrics instead of the
-end-to-end ones. After the
-window, the check compares what the window produced with the plain reference
-and prints each number compared beside its limit, last on standard error and
-under ``compared`` in the result line, the last line of standard output.
+second on each chip), reads it with the program's own spans and scopes, and
+reports the cell's per-layer metrics instead of the end-to-end ones. After
+the window, the check compares what the window produced with the plain
+reference and prints each number compared beside its limit, last on
+standard error and under ``compared`` in the result line, the last line of
+standard output.
 
 Exits 2 and prints no result when JAX finds no accelerator in
 ``perfbench/peaks.json`` or fewer chips than the cell asks for.

@@ -73,6 +73,14 @@ class Runner:
                 out.append(e)
         return out
 
+    def program_hlo(self, call) -> str:
+        """Run ``call()`` and return the optimized HLO text of the program
+        it dispatched, which names each operation's engine scope (a traced
+        set-up reads the scan body's phases from it)."""
+        from perfbench import scopes
+
+        return scopes.program_hlo(call)
+
     def hours(self, cells: list, seeds: list) -> float:
         """Simulated deployment-hours of the elements asked for; the padding
         that fills a short chunk is not work asked for and does not count."""

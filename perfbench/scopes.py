@@ -23,7 +23,9 @@ program's or the harness's) and each device's operation scopes. Its methods
 give the device time under each scope, as the union of the intervals of its
 operations (a ``conditional`` and the fusions nested in it count once), and
 split each device-idle gap between runs of the program by the engine span
-open over it.
+open over it. :func:`step_us`, :func:`gap_ms` and :func:`split` turn those
+into the numbers that the per-layer metrics (``perfbench/metrics/``) and
+``profile_cell.py`` report, so the two never disagree.
 """
 from __future__ import annotations
 
@@ -40,7 +42,10 @@ GRID = "vault.grid"
 PREPARE = ("vault.build", "vault.stack", "vault.launch")
 COLLECT = ("vault.fetch", "vault.gather")
 # the phases of the scan body
-PHASES = ("vault.churn", "vault.repair", "vault.serve", "vault.merge")
+PHASES = CHURN, REPAIR, SERVE, MERGE = (
+    "vault.churn", "vault.repair", "vault.serve", "vault.merge")
+# the parts of a gap, in the order of ``ScopedTrace.gap_split``
+GAP_PARTS = ("prepare", "collect", "harness")
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%(\S+) = ")
@@ -193,6 +198,49 @@ def _length(intervals) -> int:
 def _overlap(intervals, cover) -> int:
     """Length of ``intervals`` (disjoint) that the union ``cover`` covers."""
     return sum(_length(T.clip(cover, s, e)) for s, e in intervals)
+
+
+def step_us(trace, phases, dispatches: int, max_steps: int) -> float | None:
+    """Device microseconds a scan step under the scopes ``phases``, per
+    chip: the union of their operations' intervals in the window over
+    ``dispatches x max_steps``, the base ``scan_step_us`` divides by. None
+    for a trace without engine scopes."""
+    if not isinstance(trace, ScopedTrace) or not trace.has_scopes:
+        return None
+    return 1e6 * trace.scoped_busy_s(phases) / (dispatches * max_steps)
+
+
+def gap_ms(trace, prefix: str) -> dict:
+    """``{part: ms}`` for each of ``GAP_PARTS``: the mean, over the gaps
+    between runs of the programs ``prefix`` (those ``host_gap_ms``
+    averages), of the device-idle time under ``PREPARE`` spans, under
+    ``COLLECT`` spans, and under no ``vault.grid``. Empty for a trace
+    without the engine's spans or without such a gap."""
+    if not isinstance(trace, ScopedTrace):
+        return {}
+    gaps = trace.gap_split(prefix)
+    if not gaps or not any(name == GRID for name, _, _ in trace.spans):
+        return {}
+    return {part: 1e3 * sum(g[i] for g in gaps) / len(gaps)
+            for i, part in enumerate(GAP_PARTS)}
+
+
+def split(trace, prefix: str, dispatches: int, max_steps: int) -> dict:
+    """The whole split of a traced window: ``<phase>_step_us`` for each of
+    ``PHASES`` and ``unscoped_step_us`` (the program's device time under
+    none of them), per chip and scan step; ``gap_<part>_ms`` for each of
+    ``GAP_PARTS``. A trace without scopes or spans leaves those out."""
+    out = {}
+    for phase in PHASES:
+        us = step_us(trace, (phase,), dispatches, max_steps)
+        if us is not None:
+            out[phase.split(".", 1)[1] + "_step_us"] = us
+    if out:
+        rest = trace.program_busy_s(prefix) - trace.scoped_busy_s(PHASES)
+        out["unscoped_step_us"] = 1e6 * rest / (dispatches * max_steps)
+    out.update((f"gap_{part}_ms", ms)
+               for part, ms in gap_ms(trace, prefix).items())
+    return out
 
 
 def from_xspace(path: str, hlo_text: str = "") -> ScopedTrace:

@@ -116,6 +116,69 @@ def test_traced_line_has_breakdown(root):
     assert line["metrics"]["compiles_in_window"]["value"] == 0
 
 
+def test_listed_metrics_stay_out_of_other_cells(root, monkeypatch):
+    """A per-layer metric that lists its cells is not in the dummy cell's
+    line, and its reader is never loaded there; every unlisted one is."""
+    bench = B.Bench(root)
+    per_layer = bench.spec["per_layer"]
+    listed = {m["name"] for m in per_layer if "workloads" in m}
+    assert listed
+    assert [m["name"] for m in bench.metrics(True, CELL)] == [
+        m["name"] for m in per_layer if m["name"] not in listed]
+    assert bench.metrics(True, "d1-serve") == per_layer
+    loaded, module = [], B.Bench.module
+
+    def recording(self, kind, name):
+        if kind == "metrics":
+            loaded.append(name)
+        return module(self, kind, name)
+
+    monkeypatch.setattr(B.Bench, "module", recording)
+    line, _ = run(root, traced=True)
+    assert line["correct"]
+    assert set(loaded) == {m["name"] for m in per_layer} - listed
+    assert not listed & set(line["metrics"])
+
+
+class IdleRunner:
+    """A runner of no work."""
+
+    seeds_per_dispatch = 1
+
+    def __init__(self):
+        self.ticks = 0
+
+    def plan(self, rep):
+        return [0]
+
+    def dispatch(self, cells, seeds):
+        self.ticks += 1
+        return [{}]
+
+    def hours(self, cells, seeds):
+        return 1.0
+
+    def state_bytes_per_step(self, cells, seeds):
+        return 0
+
+
+class CountingRunner(IdleRunner):
+    """One that offers ``counters()``."""
+
+    def counters(self):
+        return {"ticks": self.ticks, "set_up": 7}
+
+
+def test_window_keeps_the_counters_difference():
+    runner = CountingRunner()
+    runner.dispatch([0], [1])  # a warm-up, before the window
+    win = B.measure(runner, SEED, 0.0, False, min_dispatches=3)
+    assert win.dispatches == 3
+    assert win.counters == {"ticks": 3, "set_up": 0}
+    # a runner without counters() leaves them out
+    assert B.measure(IdleRunner(), SEED, 0.0, False).counters is None
+
+
 def test_fault_state_unchanged(root, engine):
     engine(F.state_unchanged)
     line, _ = run(root)

@@ -1,6 +1,6 @@
-"""The reduction of the engine's spans and scopes (``perfbench/scopes.py``)
-and the split ``profile_cell.py`` reports, on synthetic traces and on one
-recorded on a v5e."""
+"""The reduction of the engine's spans and scopes (``perfbench/scopes.py``),
+the split ``profile_cell.py`` reports and the per-layer metrics read from
+it, on synthetic traces and on one recorded on a v5e."""
 import os
 import sys
 
@@ -116,7 +116,7 @@ def test_gaps_labelled_by_innermost_span():
 
 def test_profile_split_per_step_and_per_gap():
     tr = load("scoped_synthetic_trace.json")
-    split = PC.split(tr, "jit_run", dispatches=2, max_steps=4)
+    split = S.split(tr, "jit_run", dispatches=2, max_steps=4)
     assert split == pytest.approx({
         "churn_step_us": 0.25, "repair_step_us": 0.1, "serve_step_us": 0.5,
         "merge_step_us": 0.0, "unscoped_step_us": 0.15,
@@ -126,7 +126,7 @@ def test_profile_split_per_step_and_per_gap():
         "vault.grid": 7.7e-3, "vault.build": 2.5e-4, "vault.stack": 3.5e-4,
         "vault.launch": 7e-4, "vault.fetch": 6e-3, "vault.gather": 4e-4})
     # a program without scopes or spans gives no split
-    assert PC.split(load("synthetic_trace.json"), "jit_run", 2, 4) == {}
+    assert S.split(load("synthetic_trace.json"), "jit_run", 2, 4) == {}
 
 
 def test_json_round_trip_and_trim(monkeypatch):
@@ -145,19 +145,31 @@ def test_json_round_trip_and_trim(monkeypatch):
     assert cut.gap_split("jit_run") == tr.gap_split("jit_run")
 
 
-def _readings(trace):
-    run = B.Run(setup_s=1.0, window_s=1.0, hours=1.0, dispatches=3,
-                compiles_in_window=0, chips=len(trace.devices), max_steps=4,
-                state_bytes_per_step=1e6,
-                peaks={"hbm_bytes_per_s": 8.19e11},
-                program_prefix="jit_run", trace=trace)
+# the per-layer metrics accepted before the harness read the engine's labels
+ACCEPTED = ("device_idle_share", "scan_step_us", "scan_roofline_share",
+            "host_gap_ms", "compiles_in_window")
+# those that read them, each from a part of ``scopes.split``
+SPLIT = ("churn_step_us", "repair_step_us", "serve_step_us", "merge_step_us",
+         "gap_prepare_ms", "gap_collect_ms")
+
+
+def _run(trace, max_steps=4):
+    chips = len(trace.devices) if trace else 1
+    return B.Run(setup_s=1.0, window_s=1.0, hours=1.0, dispatches=3,
+                 compiles_in_window=0, chips=chips,
+                 max_steps=max_steps, state_bytes_per_step=1e6,
+                 peaks={"hbm_bytes_per_s": 8.19e11},
+                 program_prefix="jit_run", trace=trace)
+
+
+def _readings(trace, names=ACCEPTED, max_steps=4):
+    run = _run(trace, max_steps)
     bench = B.Bench(ROOT)
-    return {m["name"]: bench.module("metrics", m["name"]).read(run)
-            for m in bench.metrics(True)}
+    return {name: bench.module("metrics", name).read(run) for name in names}
 
 
-# what the accepted readers read from these fixtures before the engine had
-# spans or scopes
+# what the accepted readers read from these fixtures: the first two before
+# the engine had spans or scopes, the third before the harness read them
 @pytest.mark.parametrize("name, expect", [
     ("synthetic_trace.json",
      {"device_idle_share": 45.0, "scan_step_us": 0.4583333333333334,
@@ -167,12 +179,40 @@ def _readings(trace):
      {"device_idle_share": 68.80601057363668,
       "scan_step_us": 83228.32108333333,
       "scan_roofline_share": 0.0014670501640645607,
-      "host_gap_ms": 16.732231, "compiles_in_window": 0.0})])
+      "host_gap_ms": 16.732231, "compiles_in_window": 0.0}),
+    ("v5e_d1_serve_scoped.json",
+     {"device_idle_share": 0.24083352858390583,
+      "scan_step_us": 2674288.0707500004,
+      "scan_roofline_share": 4.565705670813515e-05,
+      "host_gap_ms": 12.9139005, "compiles_in_window": 0.0})])
 def test_accepted_readers_read_the_same(name, expect):
     plain = _readings(load(name, T.Trace))
     scoped = _readings(load(name))
     assert plain == scoped
     assert plain == pytest.approx(expect, rel=1e-6)
+
+
+def test_split_readers_read_the_split():
+    """Each reader of a part of the split returns what ``scopes.split``
+    (and so ``profile_cell.py``) gives, and nothing where the trace has no
+    scopes or engine spans."""
+    bench = B.Bench(ROOT)
+    assert set(SPLIT) <= {m["name"] for m in bench.spec["per_layer"]}
+    tr = load("v5e_d1_serve_scoped.json")
+    split = S.split(tr, "jit_run", dispatches=3, max_steps=1460)
+    assert _readings(tr, SPLIT, max_steps=1460) == {
+        name: split[name] for name in SPLIT}
+    assert split == pytest.approx({
+        "churn_step_us": 0.09041666666666667,
+        "repair_step_us": 0.09902808219178083,
+        "serve_step_us": 3.221498401826484,
+        "merge_step_us": 0.012664383561643837,
+        "unscoped_step_us": 7323.393024657535,
+        "gap_prepare_ms": 4.086912, "gap_collect_ms": 8.609763500000001,
+        "gap_harness_ms": 0.19947}, rel=1e-9)
+    for plain in (load("v5e_d1_serve_scoped.json", T.Trace),
+                  load("synthetic_trace.json"), None):
+        assert _readings(plain, SPLIT) == dict.fromkeys(SPLIT)
 
 
 def test_recorded_v5e_d1_serve_trace():

@@ -53,6 +53,9 @@ def test_benchmark_json_keys_and_names(bench):
         assert m["source"] in ("host_clock", "device_trace")
     for m in spec["per_layer"]:
         assert m["moves"] in {e["name"] for e in spec["end_to_end"]}
+        assert {"name", "unit", "better", "source", "layer", "moves"} <= set(m)
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
     # a full check of 24 cells fits its 43,200 s
     runs = 2 + 14 * 24
     assert runs * (spec["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
@@ -67,13 +70,18 @@ def test_every_file_is_found_by_name(bench):
         assert bench.module("runners", cfg["runner"]).Runner
         assert bench.module("references", cfg["reference"]).simulate_all
         assert chk["numbers"] and chk["cells_sampled"] >= 1
-    for traced in (False, True):
-        metrics = bench.metrics(traced)
-        assert metrics
-        for m in metrics:
-            assert "workloads" not in m  # every cell reports every metric
-            assert callable(bench.module("metrics", m["name"]).read)
-    assert "setup_s" in [m["name"] for m in bench.metrics(False)]
+    cells = {w["name"] for w in bench.spec["workloads"]}
+    for m in bench.spec["end_to_end"] + bench.spec["per_layer"]:
+        assert callable(bench.module("metrics", m["name"]).read)
+        # a metric scoped to cells names cells of BENCHMARK.json; an
+        # end-to-end metric is reported by every cell
+        if "workloads" in m:
+            assert m in bench.spec["per_layer"]
+            assert m["workloads"] and set(m["workloads"]) <= cells
+    for cell in cells:
+        assert "setup_s" in [m["name"] for m in bench.metrics(False, cell)]
+        assert len(bench.metrics(False, cell)) >= 2
+        assert bench.metrics(True, cell)
 
 
 def test_configs_state_source_cuts_and_layout(bench):
